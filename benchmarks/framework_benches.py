@@ -2,7 +2,8 @@
 
   chunk_calc_scaling — chunk-calculation cost vs P: sequential CCA recursion
                        vs vectorized DCA closed forms vs the Pallas kernel
-                       (interpret mode): the TPU adaptation's headline win.
+                       (interpreted off a TPU): the TPU adaptation's
+                       headline win.
   data_balance       — token-load imbalance of the DLS data scheduler vs
                        STATIC over a heavy-tailed corpus.
   straggler          — self-scheduled microbatches under a slow host.
@@ -38,14 +39,17 @@ def bench_chunk_calc_scaling(emit):
 
 
 def bench_chunk_calc_kernel(emit):
+    import jax
+
     from repro.kernels.dls_chunks import dls_chunk_schedule
 
     params = DLSParams(N=262_144, P=256)
     t0 = time.perf_counter()
-    sizes, offs = dls_chunk_schedule("fac", params, interpret=True)
+    sizes, offs = dls_chunk_schedule("fac", params)
     dt = (time.perf_counter() - t0) * 1e6
     kept = int((np.asarray(sizes) > 0).sum())
-    emit("chunk_calc/pallas_fac", dt, f"steps={kept};interpret=True")
+    emit("chunk_calc/pallas_fac", dt,
+         f"steps={kept};interpret={jax.default_backend() != 'tpu'}")
 
 
 def bench_data_balance(emit):

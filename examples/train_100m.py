@@ -13,7 +13,7 @@ from repro.models.config import ModelConfig
 
 
 def config_100m() -> ModelConfig:
-    # ~102M params: 12L, d=768, llama-style
+    # ~125M params (untied 32k embeddings: 49M of them): 12L, d=768, llama-style
     return ModelConfig(
         name="repro-100m",
         family="dense",

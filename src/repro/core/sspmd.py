@@ -26,7 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .jax_compat import axis_size
 from .techniques_jnp import (
     TECH_IDS,
     default_head_cap,
@@ -42,6 +41,7 @@ __all__ = [
     "dca_schedule_stateless",
     "dca_schedule_for_spec",
     "cca_round_assignments",
+    "cca_schedule_scan",
     "num_rounds_upper_bound",
 ]
 
@@ -56,7 +56,7 @@ def dca_round_assignments(round_state, tech_id, pv, axis_name: str):
         size 0 <=> queue exhausted (device idles / masks its work).
     """
     i0, lp0 = round_state
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     j = jax.lax.axis_index(axis_name)
 
     # Chunk calculation (distributed, the paper's Sec. 4): every device
@@ -97,7 +97,7 @@ def dca_round_assignments_stateless(round_idx, tech_id, pv, axis_name: str,
     ``dca_schedule_stateless`` derives it correctly; pass-through callers
     must do the same.
     """
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     j = jax.lax.axis_index(axis_name)
     n_total = pv[0]
     step = (jnp.asarray(round_idx, jnp.int32) * n_dev + j).astype(jnp.float32)
@@ -122,7 +122,7 @@ def dca_schedule_stateless(tech_name: str, params, axis_name: str,
     if max_rounds is None:
         max_rounds = num_rounds_upper_bound(params)
 
-    n_dev = axis_size(axis_name)  # a python int inside shard_map
+    n_dev = jax.lax.axis_size(axis_name)  # a python int inside shard_map
     # size the prefix head to the largest step index actually evaluated —
     # steps stride by the mesh axis size, which may exceed params.P
     head_cap = default_head_cap(tech_name, params, max_rounds * n_dev + n_dev)
@@ -168,7 +168,7 @@ def cca_round_assignments(round_state, tech_name: str, params, axis_name: str):
     contrasting the two execution models on-device.
     """
     i0, lp0, prev, remaining = round_state
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     j = jax.lax.axis_index(axis_name)
     p_f = jnp.float32(params.P)
 
@@ -237,5 +237,20 @@ def dca_schedule_scan(tech_name: str, params, axis_name: str, max_rounds: int = 
         return state, (off, size)
 
     init = (jnp.int32(0), jnp.int32(0))
+    _, (offs, sizes) = jax.lax.scan(body, init, None, length=max_rounds)
+    return offs, sizes
+
+
+def cca_schedule_scan(tech_name: str, params, axis_name: str, max_rounds: int = None):
+    """Full per-device CCA baseline schedule: ``cca_round_assignments`` rounds
+    under a lax.scan (inside shard_map), the counterpart of
+    ``dca_schedule_scan``.  Returns (offsets[r], sizes[r]) for this device."""
+    if max_rounds is None:
+        max_rounds = num_rounds_upper_bound(params)
+
+    def body(state, _):
+        return cca_round_assignments(state, tech_name, params, axis_name)
+
+    init = (jnp.int32(0), jnp.int32(0), jnp.float32(0.0), jnp.float32(params.N))
     _, (offs, sizes) = jax.lax.scan(body, init, None, length=max_rounds)
     return offs, sizes

@@ -73,21 +73,50 @@ def _fsc(i, pv):
     return jnp.full_like(i, jnp.floor(k))
 
 
-def _pow_ratio(i, ratio):
-    # exp/log formulation: pow with traced float exponent lowers poorly on
-    # TPU.  Guard ratio -> max(ratio, tiny) so P=1 (ratio 0) yields 0^0 = 1 at
-    # i=0 and ~0 (clamped to min_chunk) afterwards instead of nan.
-    return jnp.exp(i * jnp.log(jnp.maximum(ratio, 1e-30)))
+_POW_BITS = 24  # step indices stay below 2**24, where f32 counts exactly
+
+
+def _pow_int(base, k):
+    """base**k for 0 <= base <= 1 and integer-valued f32 k >= 0, from
+    additions and products alone.
+
+    Binary exponentiation: the product of base**(2**b) over the set bits of
+    k.  Additions and products are correctly rounded on every backend and
+    exp/log are not: the TPU's exp(i * log(0.75)) lies an ulp above 0.75**i
+    where that power is exact, and the ceil in gss turns the ulp into a
+    chunk one iteration too large.  So the result is the same bits on a TPU
+    and a CPU, and exact wherever every power it forms is an f32 (0.5**k
+    for fac; 0.75**k below k=16 for gss at P=4).
+
+    Squaring a power near 1 doubles its relative error each time, so while
+    the power is at least 1/2 its complement d = 1 - power is squared
+    instead, as d * (2 - d), which keeps d's relative error from growing.
+    k is capped at 2**24 - 1; base 0 (P=1) gives 0**0 = 1 and 0 beyond.
+    """
+    k = jnp.minimum(k, 2.0 ** _POW_BITS - 1.0)
+    power, d = base, 1.0 - base
+    powers = [power]
+    for _ in range(_POW_BITS - 1):
+        near_one = power >= 0.5
+        d = jnp.where(near_one, d * (2.0 - d), 1.0 - power * power)
+        power = jnp.where(near_one, 1.0 - d, power * power)
+        powers.append(power)
+    out = jnp.ones_like(k)
+    for b in reversed(range(_POW_BITS)):
+        take = k >= 2.0 ** b
+        out = jnp.where(take, out * powers[b], out)
+        k = jnp.where(take, k - 2.0 ** b, k)
+    return out
 
 
 def _gss(i, pv):
     ratio = (pv[_P] - 1.0) / pv[_P]
-    return jnp.ceil(_pow_ratio(i, ratio) * (pv[_N] / pv[_P]))
+    return jnp.ceil(_pow_int(ratio, i) * (pv[_N] / pv[_P]))
 
 
 def _tap(i, pv):
     ratio = (pv[_P] - 1.0) / pv[_P]
-    raw = _pow_ratio(i, ratio) * (pv[_N] / pv[_P])
+    raw = _pow_int(ratio, i) * (pv[_N] / pv[_P])
     va = pv[_VA]
     return jnp.ceil(raw + va * va / 2.0 - va * jnp.sqrt(2.0 * raw + va * va / 4.0))
 
@@ -106,7 +135,8 @@ def _tss(i, pv):
 
 def _fac(i, pv):
     i_new = jnp.floor(i / pv[_P]) + 1.0
-    return jnp.ceil(jnp.exp2(-i_new) * (pv[_N] / pv[_P]))
+    # exp2 is off by an ulp on some backends (XLA's CPU exp2(-15) > 2**-15)
+    return jnp.ceil(_pow_int(0.5, i_new) * (pv[_N] / pv[_P]))
 
 
 def _tfss(i, pv):
@@ -137,23 +167,39 @@ def _fiss(i, pv):
 def _viss(i, pv):
     k0_real = pv[_N] / (pv[_VISS_X] * pv[_P])
     batch = jnp.floor(i / pv[_P])
-    j = jnp.arange(32, dtype=jnp.float32)  # halving terms; 2^32 bounds any K0
-    terms = jnp.floor(k0_real * jnp.exp2(-j))
-    mask = j[None, :] <= batch[..., None]
-    return jnp.sum(terms[None, :] * mask, axis=-1)
+    # 32 halving terms (2^32 bounds any K0), unrolled so each is an
+    # elementwise op on i's own layout; 2.0**-j is an exact power of two.
+    total = jnp.zeros_like(i)
+    for j in range(32):
+        total = total + jnp.where(batch >= j, jnp.floor(k0_real * 2.0 ** -j), 0.0)
+    return total
 
 
-def _rnd_u01_u32(seed, i_u32):
-    x = i_u32 * jnp.uint32(0x9E3779B9) ^ (seed * jnp.uint32(0x85EBCA6B) + jnp.uint32(0xC2B2AE35))
-    x = (x ^ (x >> jnp.uint32(16))) * jnp.uint32(0x7FEB352D)
-    x = (x ^ (x >> jnp.uint32(15))) * jnp.uint32(0x846CA68B)
-    x = x ^ (x >> jnp.uint32(16))
-    return x.astype(jnp.float32) / jnp.float32(4294967296.0)
+def _i32(c: int) -> np.int32:
+    """A uint32 constant as the int32 with the same bits."""
+    return np.uint32(c).view(np.int32)
+
+
+def _rnd_u01_u32(seed, i):
+    """Counter-based uniform [0, 1) from a 32-bit mix of int32 (seed, i).
+
+    int32 multiply wraps like uint32, and the shifts are logical, so every
+    bit matches the uint32 formulation; the final uint32 -> f32 conversion is
+    the exactly-representable ``hi * 2**16`` plus ``lo`` rounded once.
+    """
+    srl = jax.lax.shift_right_logical
+    x = i * _i32(0x9E3779B9) ^ (seed * _i32(0x85EBCA6B) + _i32(0xC2B2AE35))
+    x = (x ^ srl(x, 16)) * _i32(0x7FEB352D)
+    x = (x ^ srl(x, 15)) * _i32(0x846CA68B)
+    x = x ^ srl(x, 16)
+    hi = srl(x, 16).astype(jnp.float32)
+    lo = (x & 0xFFFF).astype(jnp.float32)
+    return (hi * 65536.0 + lo) / jnp.float32(4294967296.0)
 
 
 def _rnd(i, pv):
     hi = jnp.maximum(jnp.floor(pv[_N] / pv[_P]), 1.0)
-    u = _rnd_u01_u32(pv[_SEED].astype(jnp.uint32), i.astype(jnp.uint32))
+    u = _rnd_u01_u32(pv[_SEED].astype(jnp.int32), i.astype(jnp.int32))
     return jnp.floor(u * hi) + 1.0
 
 
@@ -161,7 +207,7 @@ def _pls(i, pv):
     static_chunk = jnp.floor(pv[_N] * pv[_SWR] / pv[_P])
     n_dyn = pv[_N] - static_chunk * pv[_P]
     ratio = (pv[_P] - 1.0) / pv[_P]
-    dyn = jnp.ceil(_pow_ratio(jnp.maximum(i - pv[_P], 0.0), ratio) * (n_dyn / pv[_P]))
+    dyn = jnp.ceil(_pow_int(ratio, jnp.maximum(i - pv[_P], 0.0)) * (n_dyn / pv[_P]))
     return jnp.where(i < pv[_P], static_chunk, dyn)
 
 
@@ -209,6 +255,19 @@ def _tri(x):
     return x * (x - 1.0) * 0.5
 
 
+def _index_tile(n: int) -> jnp.ndarray:
+    """f32 indices 0, 1, ... covering [0, n) as an (R, 128) tile, R % 8 == 0.
+
+    Built from integer iotas in the TPU's (8, 128) vreg layout, so the
+    bounded summations below lower inside a Pallas kernel as well as in XLA;
+    entries >= n are padding that callers mask out.
+    """
+    rows = -(-max(n, 1) // 1024) * 8
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
+    return (r * 128 + c).astype(jnp.float32)
+
+
 def _head_prefix(fn, i, pv, head_cap: int):
     """Bounded head summation + constant-mc tail (gss/tap/pls/rnd).
 
@@ -217,25 +276,25 @@ def _head_prefix(fn, i, pv, head_cap: int):
     evaluated step range).
     """
     i = jnp.asarray(i, dtype=jnp.float32)
-    js = jnp.arange(max(head_cap, 1), dtype=jnp.float32)
+    cap = float(max(head_cap, 1))
+    js = _index_tile(int(cap))
     sz = _clipped_size(fn, js, pv)
-    mask = js < i[..., None]
-    head = jnp.sum(sz * mask, axis=-1)
-    return head + jnp.maximum(i - float(max(head_cap, 1)), 0.0) * _mce(pv)
+    head = jnp.sum(sz * (js < jnp.minimum(i, cap)[..., None, None]), axis=(-2, -1))
+    return head + jnp.maximum(i - cap, 0.0) * _mce(pv)
 
 
 def _batched_prefix(fn, i, pv, bcap: int):
     """Prefix for batched techniques whose batch value saturates by bcap-1."""
     i = jnp.asarray(i, dtype=jnp.float32)
     p_ = pv[_P]
-    bs = jnp.arange(bcap, dtype=jnp.float32)
-    vb = _clipped_size(fn, bs * p_, pv)  # [bcap] batch values
+    bs = _index_tile(bcap)
+    vb = _clipped_size(fn, bs * p_, pv)  # batch values (padding masked below)
     b = jnp.floor(i / p_)
     rr = i - b * p_
     bc = jnp.minimum(b, float(bcap - 1))
-    cum = jnp.sum(vb * (bs < bc[..., None]), axis=-1)
-    vcur = jnp.sum(vb * (bs == bc[..., None]), axis=-1)
-    tail = (b - bc) * vb[bcap - 1]
+    cum = jnp.sum(vb * (bs < bc[..., None, None]), axis=(-2, -1))
+    vcur = jnp.sum(vb * (bs == bc[..., None, None]), axis=(-2, -1))
+    tail = (b - bc) * jnp.sum(vb * (bs == float(bcap - 1)), axis=(-2, -1))
     return p_ * (cum + tail) + rr * vcur
 
 
@@ -331,7 +390,7 @@ def default_head_cap(technique: str, params: DLSParams, max_steps: int) -> int:
     """Static head length for ``prefix_for_steps``' bounded summations.
 
     For gss/tap the head covers the geometric decay down to the min chunk
-    (plus a safety margin absorbing f32 exp/log boundary jitter); pls adds its
+    (plus a safety margin absorbing f32 rounding at the boundary); pls adds its
     P static chunks; rnd has no analytic bound, so its head must span every
     step the caller will evaluate.  Exact-series techniques return 1 (unused).
     """

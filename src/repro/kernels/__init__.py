@@ -14,8 +14,10 @@ Three kernels, each a subpackage with:
                    jamba) — sequential grid over sequence chunks with the SSM
                    state carried in VMEM scratch
 
-Kernels are validated in interpret mode on CPU (this container has no TPU);
-BlockSpecs are shaped for v5e VMEM/MXU (128-aligned tiles).
+Off a TPU the kernels run through the Pallas interpreter, which the CPU
+tests use; tests/test_tpu_compile.py compiles them for a described v5e, and
+chip_smoke.py runs the dls_chunks kernel on the chip.  BlockSpecs are shaped
+for v5e VMEM/MXU (128-aligned tiles).
 """
 
 from . import dls_chunks, flash_attention, mamba_scan  # noqa: F401

@@ -38,8 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.jax_compat import pallas_tpu_compiler_params
 from repro.core.techniques_jnp import prefix_for_steps, sizes_for_steps
 
 ROWS = 8  # sublanes per tile
@@ -48,15 +48,36 @@ TILE = ROWS * LANES  # scheduling steps per grid step
 
 MAX_N = 2 ** 23  # f32-exactness bound for the analytic offsets (see above)
 
-_CompilerParams = pallas_tpu_compiler_params()
+
+def _inclusive_scan(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Inclusive prefix sum along ``axis`` by log-step shift-and-add.
+
+    Mosaic has no cumsum; each step adds the value ``s`` places back, taken
+    from a rotate.  Which way the hardware rotate turns is read from a
+    rotated iota rather than assumed: of the two rotates by ``s`` and
+    ``n - s``, the one whose iota shows index ``k - s`` at ``k`` is used.
+    Every partial sum is a sum of a contiguous run of non-negative
+    integers, so it is exact wherever the true prefix is below 2**24.
+    """
+    n = x.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    s = 1
+    while s < n:
+        fwd = pltpu.roll(x, s, axis)
+        back = pltpu.roll(x, n - s, axis)
+        src = pltpu.roll(idx, s, axis)
+        prev = jnp.where(src == idx - s, fwd, back)
+        x = x + jnp.where(idx >= s, prev, 0.0)
+        s *= 2
+    return x
 
 
 def _flat_exclusive_cumsum(x: jnp.ndarray) -> jnp.ndarray:
     """Exclusive prefix sum of an (ROWS, LANES) tile in row-major order."""
-    within_row = jnp.cumsum(x, axis=1) - x  # exclusive along lanes
-    row_totals = jnp.sum(x, axis=1)  # (ROWS,)
-    row_prefix = jnp.cumsum(row_totals) - row_totals  # exclusive over rows
-    return within_row + row_prefix[:, None]
+    within_row = _inclusive_scan(x, 1) - x  # exclusive along lanes
+    row_totals = jnp.broadcast_to(jnp.sum(x, axis=1, keepdims=True), x.shape)
+    row_prefix = _inclusive_scan(row_totals, 0) - row_totals  # over rows
+    return within_row + row_prefix
 
 
 def _dls_chunks_kernel(sizes_ref, offsets_ref, *, tech_id, pv_tuple, head_cap):
@@ -93,7 +114,7 @@ def dls_chunks_pallas(
     pv_tuple: tuple,
     num_tiles: int,
     head_cap: int = 4096,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Build the pallas_call for ``num_tiles`` tiles of TILE scheduling steps.
 
@@ -101,6 +122,8 @@ def dls_chunks_pallas(
     row-major step order.  ``pv_tuple`` is the packed DLSParams vector as a
     static tuple of floats (see techniques_jnp.pack_params); ``head_cap`` the
     static head length for prefix summation (techniques_jnp.default_head_cap).
+    ``interpret`` runs the body through the Pallas interpreter, for hosts
+    without a TPU.
     """
     if pv_tuple[0] > MAX_N:
         raise ValueError(
@@ -122,7 +145,7 @@ def dls_chunks_pallas(
             jax.ShapeDtypeStruct((out_rows, LANES), jnp.int32),
             jax.ShapeDtypeStruct((out_rows, LANES), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),  # stateless tiles => any order
         ),
         interpret=interpret,

@@ -5,6 +5,7 @@ from __future__ import annotations
 import math
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -26,25 +27,42 @@ def _default_max_steps(technique: str, params: DLSParams) -> int:
     return min(drain_steps(technique, params) + TILE, upper)
 
 
-def dls_chunk_schedule(
-    technique: str,
-    params: DLSParams,
-    max_steps: int | None = None,
-    interpret: bool = True,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Compute the full DCA schedule on-device.
+def _platform() -> str:
+    """Platform of the device an eager call lands on (``jax.default_device``
+    if one is set, else the default backend)."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
 
-    Returns (sizes, offsets) int32 [S_padded] in step order; entries with
-    size 0 are past the end of the loop.  ``interpret=True`` runs the kernel
-    body on CPU (this container); pass False on real TPU.
-    """
-    tech_id = TECH_IDS[technique]
+
+def kernel_args(technique: str, params: DLSParams, max_steps: int | None = None):
+    """Static arguments of ``dls_chunks_pallas`` for one schedule:
+    (tech_id, pv_tuple, num_tiles, head_cap)."""
     if max_steps is None:
         max_steps = _default_max_steps(technique, params)
     num_tiles = max(int(math.ceil(max_steps / TILE)), 1)
     head_cap = default_head_cap(technique, params, num_tiles * TILE)
-    pv_tuple = tuple(float(x) for x in np.asarray(pack_params(params)))
+    with jax.ensure_compile_time_eval():  # static even under an outer jit
+        pv_tuple = tuple(float(x) for x in np.asarray(pack_params(params)))
+    return TECH_IDS[technique], pv_tuple, num_tiles, head_cap
+
+
+def dls_chunk_schedule(
+    technique: str,
+    params: DLSParams,
+    max_steps: int | None = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Compute the full DCA schedule on-device.
+
+    Returns (sizes, offsets) int32 [S_padded] in step order; entries with
+    size 0 are past the end of the loop.  The platform of the device the call
+    lands on decides how the kernel runs: compiled by Mosaic on a TPU, through
+    the Pallas interpreter anywhere else.
+    """
+    tech_id, pv_tuple, num_tiles, head_cap = kernel_args(technique, params, max_steps)
     sizes, offsets = dls_chunks_pallas(
-        tech_id, pv_tuple, num_tiles, head_cap=head_cap, interpret=interpret
+        tech_id, pv_tuple, num_tiles, head_cap=head_cap,
+        interpret=_platform() != "tpu",
     )
     return sizes.reshape(-1), offsets.reshape(-1)

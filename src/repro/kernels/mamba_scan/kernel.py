@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.jax_compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 
 def _mamba_kernel(
     x_ref, dt_ref, a_ref, b_ref, c_ref, dskip_ref,  # blocks, see specs below
@@ -69,7 +65,7 @@ def mamba_scan_pallas(
     *,
     block_l: int = 128,
     block_d: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     bsz, l, d = x.shape
     n = a.shape[1]
@@ -92,7 +88,7 @@ def mamba_scan_pallas(
         out_specs=pl.BlockSpec((1, block_l, block_d), lambda b_, di, li: (b_, li, di)),
         out_shape=jax.ShapeDtypeStruct((bsz, l, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

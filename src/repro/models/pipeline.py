@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.jax_compat import axis_size, shard_map
-
 __all__ = ["gpipe_forward", "bubble_fraction"]
 
 
@@ -43,7 +41,7 @@ def gpipe_forward(
 
     def stage_fn(params_local, micro_in):
         s_idx = jax.lax.axis_index(axis)
-        s_total = axis_size(axis)
+        s_total = jax.lax.axis_size(axis)
 
         def apply_local(h):
             def body(c, pl):
@@ -83,7 +81,7 @@ def gpipe_forward(
     param_specs = jax.tree.map(
         lambda x: P(axis, *([None] * (x.ndim - 1))), stacked_params
     )
-    return shard_map(
+    return jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(param_specs, P()),

@@ -4,6 +4,9 @@
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
+
+REPO_ROOT = Path(__file__).resolve().parent.parent
 
 
 PROG = textwrap.dedent("""
@@ -52,7 +55,7 @@ PROG = textwrap.dedent("""
 def test_rules_valid_for_all_cells():
     res = subprocess.run(
         [sys.executable, "-c", PROG], capture_output=True, text=True, timeout=300,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}, cwd="/root/repo",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}, cwd=REPO_ROOT,
     )
     assert "RULES_OK" in res.stdout, f"stdout={res.stdout}\nstderr={res.stderr[-2500:]}"
 
@@ -63,7 +66,6 @@ COMPRESS_PROG = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.core.jax_compat import shard_map
     from repro.optim.compression import ef_topk_allreduce
 
     mesh = jax.make_mesh((4,), ("dp",))
@@ -73,20 +75,23 @@ COMPRESS_PROG = textwrap.dedent("""
     def f(g, e):
         return ef_topk_allreduce(g, e, "dp", ratio=0.25)
 
-    out, err = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
+    out, err = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
                                      out_specs=(P("dp"), P("dp"))))(g, e)
+    # index host copies: the mesh's axes are Explicit, so the sharded
+    # results cannot be indexed as plain device arrays
+    g, out, err = np.asarray(g), np.asarray(out), np.asarray(err)
     # every device's reduced gradient equals the mean of the compressed locals
     comp = []
     for i in range(4):
-        gi = np.asarray(g[i])
+        gi = g[i]
         k = int(256 * 0.25)
         thr = np.sort(np.abs(gi))[-k]
         comp.append(np.where(np.abs(gi) >= thr, gi, 0.0))
     expected = np.mean(comp, axis=0)
     for i in range(4):
-        np.testing.assert_allclose(np.asarray(out[i]), expected, atol=1e-5)
+        np.testing.assert_allclose(out[i], expected, atol=1e-5)
     # error feedback holds the residual
-    np.testing.assert_allclose(np.asarray(err[0]), np.asarray(g[0]) - comp[0], atol=1e-5)
+    np.testing.assert_allclose(err[0], g[0] - comp[0], atol=1e-5)
     print("COMPRESS_OK")
 """)
 
@@ -94,6 +99,6 @@ COMPRESS_PROG = textwrap.dedent("""
 def test_ef_allreduce_in_shard_map_subprocess():
     res = subprocess.run(
         [sys.executable, "-c", COMPRESS_PROG], capture_output=True, text=True,
-        timeout=300, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}, cwd="/root/repo",
+        timeout=300, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}, cwd=REPO_ROOT,
     )
     assert "COMPRESS_OK" in res.stdout, f"stdout={res.stdout}\nstderr={res.stderr[-2500:]}"

@@ -8,6 +8,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count=4.
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,6 @@ def test_gpipe_four_stages_subprocess():
         [sys.executable, "-c", SUBPROCESS_PROG],
         capture_output=True, text=True, timeout=300,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo",
+        cwd=Path(__file__).resolve().parent.parent,
     )
     assert "PIPELINE_OK" in res.stdout, f"stdout={res.stdout}\nstderr={res.stderr[-2000:]}"

@@ -83,6 +83,24 @@ def test_jnp_prefix_consistent_with_jnp_sizes(tech, n, p):
     assert ok.all(), f"{tech} N={n} P={p}: first bad i={np.argmin(ok)}"
 
 
+@pytest.mark.parametrize("p,exact_below", [(2, 127), (4, 16), (37, 1), (256, 4), (1024, 3)])
+def test_pow_int_exact_powers_and_bounded_error(p, exact_below):
+    """gss/tap/pls form ((p-1)/p)**k from additions and products, so the
+    result is exact while every power it forms is an f32, and within 128 ulp
+    of the float64 power of the same f32 base up to k = 8191."""
+    import jax.numpy as jnp
+
+    from repro.core.techniques_jnp import _pow_int
+
+    base = np.float32((p - 1) / p)
+    k = np.arange(8192, dtype=np.float32)
+    got = np.asarray(_pow_int(base, jnp.asarray(k))).astype(np.float64)
+    want = np.float64(base) ** k.astype(np.float64)
+    np.testing.assert_array_equal(got[:exact_below], want[:exact_below])
+    normal = want > 1e-30
+    np.testing.assert_allclose(got[normal], want[normal], rtol=128 * 2.0 ** -24)
+
+
 @pytest.mark.parametrize("tech", DCA_TECHS)
 def test_chunk_of_step_prefix_path(tech):
     """O(1) per-PE chunk lookup (closed-form prefix) matches the schedule."""
@@ -107,7 +125,6 @@ def test_stateless_sspmd_matches_scan():
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.core.jax_compat import shard_map
     from repro.core.sspmd import dca_schedule_scan, dca_schedule_stateless
 
     mesh = Mesh(np.array(jax.devices()), ("pe",))
@@ -122,12 +139,12 @@ def test_stateless_sspmd_matches_scan():
             offs, sizes = dca_schedule_stateless(tech, params, "pe")
             return offs[None], sizes[None]
 
-        o1, s1 = (np.ravel(x) for x in jax.jit(shard_map(
+        o1, s1 = (np.ravel(x) for x in jax.jit(jax.shard_map(
             scan_fn, mesh=mesh, in_specs=(), out_specs=(P("pe"), P("pe")),
-            check_rep=False))())
-        o2, s2 = (np.ravel(x) for x in jax.jit(shard_map(
+            check_vma=False))())
+        o2, s2 = (np.ravel(x) for x in jax.jit(jax.shard_map(
             stateless_fn, mesh=mesh, in_specs=(), out_specs=(P("pe"), P("pe")),
-            check_rep=False))())
+            check_vma=False))())
         np.testing.assert_array_equal(s1, s2, err_msg=tech)
         keep = s1 > 0
         np.testing.assert_array_equal(o1[keep], o2[keep], err_msg=tech)

@@ -11,8 +11,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.jax_compat import shard_map
-
 from repro.core.schedule import build_schedule_dca
 from repro.core.sspmd import dca_schedule_scan, num_rounds_upper_bound
 from repro.core.techniques import DLSParams
@@ -35,9 +33,9 @@ def test_dca_schedule_scan_covers_loop(tech):
             offs, sizes = dca_schedule_scan(tech, params, "pe")
             return offs[None], sizes[None]
 
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=(), out_specs=(P("pe"), P("pe")),
-            check_rep=False,
+            check_vma=False,
         )()
 
     offs, sizes = run()
@@ -67,8 +65,8 @@ def test_dca_scan_matches_host_schedule(tech):
             offs, sizes = dca_schedule_scan(tech, params, "pe")
             return offs[None], sizes[None]
 
-        return shard_map(inner, mesh=mesh, in_specs=(), out_specs=(P("pe"), P("pe")),
-                         check_rep=False)()
+        return jax.shard_map(inner, mesh=mesh, in_specs=(), out_specs=(P("pe"), P("pe")),
+                             check_vma=False)()
 
     offs, sizes = run()
     dev_pairs = sorted(
