@@ -28,6 +28,10 @@ real execution, sampled at chunk start on a shared run clock.  The legacy
 ``calc_delay_s`` scalar is kept as the constant-scenario alias (same
 behaviour as before the injection layer existed).
 
+With ``core/tracing.py`` switched on when ``run`` starts, the workers run a
+loop that opens ``claim`` and ``report`` spans and records each chunk's lock
+wait and the worker thread's CPU time; otherwise they open no span.
+
 Used by: data/scheduler.py (document->rank assignment), runtime/straggler.py
 (microbatch claims), examples/slowdown_reproduction.py, and the cross-engine
 conformance suite (tests/test_conformance.py).
@@ -41,6 +45,7 @@ from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
+from . import tracing
 from .source import ChunkSource, resolve_mode, _source_for
 from .techniques import DLSParams, auto_technique, get_technique
 
@@ -82,11 +87,19 @@ def _resolve_scenario(scenario, calc_delay_s: float, P: int):
 
 
 class ChunkRecord:
-    __slots__ = ("step", "lo", "hi", "worker", "t_claim", "t_done")
+    """One executed chunk.  ``t_req``: the worker asked for it; ``t_claim``:
+    it had it, delays paid; ``t_done``: ``fn`` returned (``perf_counter``
+    seconds).  With tracing on, ``wait_s`` is the claim's lock wait (where
+    the source knows it) and ``cpu_s`` the worker thread's CPU time in
+    ``fn``; otherwise both are ``None``."""
 
-    def __init__(self, step, lo, hi, worker, t_claim, t_done):
+    __slots__ = ("step", "lo", "hi", "worker", "t_claim", "t_done", "t_req", "wait_s", "cpu_s")
+
+    def __init__(self, step, lo, hi, worker, t_claim, t_done, t_req=None, wait_s=None,
+                 cpu_s=None):
         self.step, self.lo, self.hi = step, lo, hi
         self.worker, self.t_claim, self.t_done = worker, t_claim, t_done
+        self.t_req, self.wait_s, self.cpu_s = t_req, wait_s, cpu_s
 
     def __repr__(self):
         return f"ChunkRecord(step={self.step}, [{self.lo},{self.hi}), w={self.worker})"
@@ -203,10 +216,19 @@ class SelfSchedulingExecutor:
         )
         serialized = self.source.serialized
         amortized = bool(getattr(self.source, "amortizes_network", False))
+        delay = self._loop_delay()
+        pays = net_claims or bool(delay)
+
+        def pay(wid: int):
+            if net_claims:
+                nd = injector.claim_delay(wid, serialized, amortized)
+                if nd:
+                    time.sleep(nd)  # claim transport, concurrent wire legs
+            if delay:
+                time.sleep(delay)  # calculation slowdown, concurrent (DCA)
 
         def worker(wid: int):
             source = self.source
-            delay = self._loop_delay()
             # per-chunk speed stretching, sampled at chunk start (scenario)
             run_fn = injector.bind(fn, wid) if injector is not None else fn
             while True:
@@ -214,22 +236,45 @@ class SelfSchedulingExecutor:
                 chunk = source.claim(wid)
                 if chunk is None:
                     return
-                if net_claims:
-                    nd = injector.claim_delay(wid, serialized, amortized)
-                    if nd:
-                        time.sleep(nd)  # claim transport, concurrent wire legs
-                if delay:
-                    time.sleep(delay)  # calculation slowdown, concurrent (DCA)
+                if pays:
+                    pay(wid)
                 t_claim = time.perf_counter()
                 run_fn(chunk.lo, chunk.hi)
                 t_done = time.perf_counter()
                 source.report(chunk, t_done - t_claim, overhead=t_claim - t_req)
                 with self._records_lock:
                     self.records.append(
-                        ChunkRecord(chunk.step, chunk.lo, chunk.hi, wid, t_claim, t_done)
+                        ChunkRecord(chunk.step, chunk.lo, chunk.hi, wid, t_claim, t_done, t_req)
                     )
 
-        threads = [threading.Thread(target=worker, args=(w,)) for w in range(n_workers)]
+        def traced_worker(wid: int):
+            # the same loop with spans (claim, report) and the thread's CPU
+            # time in fn; kept apart so that the untraced loop pays nothing
+            source = self.source
+            run_fn = injector.bind(fn, wid) if injector is not None else fn
+            span, cpu = tracing.span, time.thread_time
+            while True:
+                t_req = time.perf_counter()
+                with span("claim"):
+                    chunk, wait_s = source.claim_timed(wid)
+                    if chunk is None:
+                        return
+                    if pays:
+                        pay(wid)
+                t_claim = time.perf_counter()
+                c0 = cpu()
+                run_fn(chunk.lo, chunk.hi)
+                cpu_s = cpu() - c0
+                t_done = time.perf_counter()
+                with span("report"):
+                    source.report(chunk, t_done - t_claim, overhead=t_claim - t_req)
+                    record = ChunkRecord(chunk.step, chunk.lo, chunk.hi, wid, t_claim, t_done,
+                                         t_req, wait_s, cpu_s)
+                    with self._records_lock:
+                        self.records.append(record)
+
+        loop = traced_worker if tracing.enabled() else worker
+        threads = [threading.Thread(target=loop, args=(w,)) for w in range(n_workers)]
         for t in threads:
             t.start()
         for t in threads:

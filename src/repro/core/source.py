@@ -58,6 +58,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from . import tracing
 from .schedule import Schedule, build_schedule_cca, build_schedule_dca
 from .techniques import (
     AWFFeedback,
@@ -182,6 +183,11 @@ class ChunkSource:
 
     def claim(self, worker: int = 0) -> Optional[Chunk]:  # pragma: no cover
         raise NotImplementedError
+
+    def claim_timed(self, worker: int = 0) -> Tuple[Optional[Chunk], Optional[float]]:
+        """``claim`` and the seconds it waited for a lock (``None``: not
+        known).  Called by the executor's traced loop only."""
+        return self.claim(worker), None
 
     def report(self, chunk: Chunk, elapsed: float, overhead: float = 0.0) -> None:
         """Execution feedback: ``elapsed`` is the chunk's compute time,
@@ -355,6 +361,9 @@ class StaticSource(ChunkSource):
         # closed form / table lookup — outside any lock
         return Chunk(step, self._lo[step], self._hi[step], worker)
 
+    def claim_timed(self, worker: int = 0) -> Tuple[Optional[Chunk], Optional[float]]:
+        return self.claim(worker), 0.0  # lock-free: it never waits
+
     def drained(self) -> bool:
         return self._exhausted or self.claimed >= self.schedule.num_steps
 
@@ -446,8 +455,15 @@ class CriticalSectionSource(ChunkSource):
         self._prev_raw = 0.0
 
     def claim(self, worker: int = 0) -> Optional[Chunk]:
+        return self._claim(worker, self._lock)
+
+    def claim_timed(self, worker: int = 0) -> Tuple[Optional[Chunk], Optional[float]]:
+        lock = tracing.TimedLock(self._lock)
+        return self._claim(worker, lock), lock.wait_s
+
+    def _claim(self, worker: int, lock) -> Optional[Chunk]:
         worker = worker % self.params.P  # PE slot (feedback arrays are [P])
-        with self._lock:
+        with lock:
             if self._remaining <= 0:
                 return None
             if self.calc_delay_s:

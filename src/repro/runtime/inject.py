@@ -459,6 +459,12 @@ class InjectedSource(ChunkSource):
             time.sleep(self.delay_calc_s)  # on the claimer, concurrent
         return chunk
 
+    def claim_timed(self, worker: int = 0):
+        chunk, wait_s = self.inner.claim_timed(worker)
+        if chunk is not None and self.delay_calc_s:
+            time.sleep(self.delay_calc_s)
+        return chunk, wait_s
+
     def report(self, chunk: Chunk, elapsed: float, overhead: float = 0.0) -> None:
         self.inner.report(chunk, elapsed, overhead)
 
