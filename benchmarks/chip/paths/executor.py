@@ -1,9 +1,13 @@
 """Host path: ``make_source(ScheduleSpec)`` feeding ``SelfSchedulingExecutor``.
 
 A frame builds a fresh source, and ``pes`` worker threads self-schedule its
-chunks.  A worker runs the device body on its chunk, one call per tile, and
-waits for the result before it claims the next chunk, as a PE of the paper
-finishes its chunk first.  The frame ends when every chunk's result is ready.
+chunks.  A worker hands its chunk to the device-body adapter, which runs the
+device body on it one call per tile and waits for each result, as a PE of
+the paper finishes its chunk before it claims the next.  The frame ends when
+every chunk's result is ready.
+
+A traced frame runs under the program's tracing switch: the executor, the
+sources and the adapter open their spans and stamp their records.
 """
 
 from __future__ import annotations
@@ -13,29 +17,17 @@ import time
 
 import numpy as np
 
-SPANS = ("make_source", "claim", "body", "block")
+from device_body import DeviceBody
+from repro.core import tracing
 
-
-class _TracedSource:
-    """The source, with a ``claim`` span around each claim."""
-
-    def __init__(self, source):
-        self._source = source
-
-    def claim(self, worker: int = 0):
-        import jax
-
-        with jax.profiler.TraceAnnotation("claim"):
-            return self._source.claim(worker)
-
-    def __getattr__(self, name):
-        return getattr(self._source, name)
+SPANS = tuple(dict.fromkeys(("make_source", "dispatch", "block") + tracing.SPANS))
 
 
 class Frame:
-    def __init__(self, view, records, tiles, source_build_s, n):
+    def __init__(self, view, records, body, source_build_s, n):
         self.view, self.records, self.source_build_s = view, records, source_build_s
-        self._tiles = tiles
+        self._results = body.results
+        self.stamps = body.stamps
         self.complete = sum(r.hi - r.lo for r in records) == n
 
     def chunks(self):
@@ -44,7 +36,7 @@ class Frame:
 
     def tiles(self):
         """(lo, size, results) of every body call, on the host."""
-        return [(lo, size, np.asarray(out).reshape(-1)) for lo, size, out in self._tiles]
+        return [(lo, size, np.asarray(out).reshape(-1)) for lo, size, out in self._results]
 
 
 class Runner:
@@ -64,30 +56,21 @@ class Runner:
                                  mode=traffic["mode"], scenario=self.scenario)
 
     def frame(self, view, traced: bool = False) -> Frame:
+        with tracing.on() if traced else contextlib.nullcontext():
+            return self._frame(view)
+
+    def _frame(self, view) -> Frame:
         import jax
 
         from repro.core.executor import SelfSchedulingExecutor
         from repro.core.source import make_source
 
-        span = jax.profiler.TraceAnnotation if traced else (lambda _: contextlib.nullcontext())
-        tile, step, tiles = self.tile, self.tile_size, []
-        view_dev = jax.device_put(view, self.device)
-
-        def fn(lo, hi):
-            for a in range(lo, hi, step):
-                size = min(step, hi - a)
-                with span("body"):
-                    out = tile(np.array([a, size], np.int32), view_dev)
-                with span("block"):
-                    out.block_until_ready()
-                tiles.append((a, size, out))
-
+        body = DeviceBody(self.tile, self.tile_size, jax.device_put(view, self.device))
         t0 = time.perf_counter()
-        with span("make_source"):
+        with tracing.span("make_source") if tracing.enabled() else contextlib.nullcontext():
             source = make_source(self.spec)
         build_s = time.perf_counter() - t0
         ex = SelfSchedulingExecutor(self.spec.technique, self.spec.to_params(), self.spec.mode,
-                                    source=_TracedSource(source) if traced else source,
-                                    scenario=self.scenario)
-        ex.run(fn, n_workers=self.workers)
-        return Frame(view, ex.records, tiles, build_s, self.n)
+                                    source=source, scenario=self.scenario)
+        ex.run(body, n_workers=self.workers)
+        return Frame(view, ex.records, body, build_s, self.n)

@@ -161,9 +161,18 @@ def test_traced_line_has_breakdown_and_layer_metrics():
     assert res["correct"]
     assert list(res) == KEYS[:5] + ["breakdown", "checks"]
     # the CPU has no device plane, so device_idle_share finds nothing to read
-    assert set(res["metrics"]) == {"source_build_ms", "claim_gap_us", "chunk_exec_us"}
+    assert set(res["metrics"]) == {"source_build_ms", "claim_gap_us", "chunk_exec_us",
+                                   "claim_us", "run_cpu_us", "dispatch_us", "block_us"}
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
     assert res["device"]["window_s"] > 0
+
+
+@pytest.mark.parametrize("name", HOST_CELLS[1:])
+def test_traced_line_reads_the_programs_spans_in_every_host_cell(name):
+    res = scenario(name, trace=True)
+    assert res["correct"]
+    assert set(res["metrics"]) == set(run.load_cell(name).per_layer) - {"device_idle_share"}
+    assert ("lock_wait_us" in res["metrics"]) == name.endswith("slow100")
 
 
 @pytest.mark.parametrize("fault,number", [("bf16", "pixels_differing"),

@@ -58,7 +58,8 @@ def test_windows_rotate_and_read_cpu_only_where_tracing_was_on(lines):
     for ln in windows:
         assert ln["frames"] >= 1 and ln["loop_s"] > 0
         assert ln["claim_us"] > 0 and ln["chunk_exec_us"] > 0
-        assert (ln["run_cpu_us"] is None) == (ln["mode"] == "off")
+        for key in ("run_cpu_us", "dispatch_us", "block_us"):
+            assert (ln[key] is None) == (ln["mode"] == "off"), key
     profiled = [ln for ln in windows if ln["mode"] == "profiler"]
     # the CPU has no device plane: the whole window is one idle stretch,
     # named by the spans open at its midpoint

@@ -9,8 +9,9 @@ In one process, with the cell's path and the program's tracing switch
 
 1. from the first (compiling) frame on, ``--warm-frames`` frames with tracing
    on and no profiler, one line each: the frame's time, ``claim_us``,
-   ``lock_wait_us``, ``run_cpu_us``, ``chunk_exec_us``, ``claim_gap_us``, and
-   the collections (per generation) and seconds of GC in it;
+   ``lock_wait_us``, ``run_cpu_us``, ``dispatch_us``, ``block_us``,
+   ``chunk_exec_us``, ``claim_gap_us``, and the collections (per
+   generation) and seconds of GC in it;
 2. untraced frames until ``run.WARM_CHUNKS`` chunks have run in all, as a
    run's set-up;
 3. ``--rounds`` rounds of three windows of ``--seconds`` each, in an order
@@ -38,7 +39,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import run  # noqa: E402
 
-READINGS = ("claim_us", "lock_wait_us", "run_cpu_us", "chunk_exec_us", "claim_gap_us")
+READINGS = ("claim_us", "lock_wait_us", "run_cpu_us", "dispatch_us", "block_us", "chunk_exec_us",
+            "claim_gap_us")
 MODES = ("off", "on", "profiler")
 
 
@@ -68,7 +70,6 @@ def probe(cell, devices, seed, warm_frames, rounds, seconds):
                                                             gc1["collections"])],
                    "gc_s": gc1["seconds"] - gc0["seconds"]}
     run.warm(app, cfg, runner, max(0, run.WARM_CHUNKS - done))
-    spans = tuple(dict.fromkeys(runner.spans + tracing.SPANS))
     for r in range(rounds):
         for mode in MODES[r % 3:] + MODES[:r % 3]:
             line = {"phase": "window", "round": r, "mode": mode}
@@ -79,7 +80,7 @@ def probe(cell, devices, seed, warm_frames, rounds, seconds):
                     if mode == "on":
                         win = run.measure(runner, views, seconds, seed)
                     else:
-                        win, reduced = run.traced(runner, views, seconds, seed, spans)
+                        win, reduced = run.traced(runner, views, seconds, seed, runner.spans)
             line.update(frames=len(win.times), loop_s=win.window_s / len(win.times),
                         **readings(win.frames or win.kept))
             if mode == "profiler":
