@@ -8,6 +8,8 @@ Layers:
                   CriticalSection / Adaptive / Hierarchical backends)
   simulator       discrete-event CCA/DCA comparison with delay injection
   executor        thread-based self-scheduling runtime (LB4MPI analogue)
+  device_body     the device-call layer: a chunk as tile calls of a device
+                  body, each waited for
   hierarchical    two-level DCA (the paper's HDSS-style companion scheme)
   sspmd           device-level BSP self-scheduler under shard_map
   api             LB4MPI-compatible facade (Listing 1 of the paper)

@@ -13,7 +13,10 @@ Spans the program opens (``SPANS``):
   calculation delay and network legs (``core/executor.py``);
 * ``lock_wait``: a claimer waiting for a source's lock (``TimedLock``);
 * ``report``: feedback to the source and the record append;
-* ``gc``: a garbage collection, on the thread that triggered it.
+* ``gc``: a garbage collection, on the thread that triggered it;
+* ``dispatch``: a device body's call, from the call to its return with the
+  result not yet waited for (``core/device_body.py``);
+* ``block``: the wait for that result (``block_until_ready``).
 
 While tracing is on, ``gc_stats()`` also counts collections per generation
 and the seconds they took.
@@ -27,7 +30,7 @@ import time
 
 __all__ = ["SPANS", "enabled", "on", "span", "gc_stats", "TimedLock"]
 
-SPANS = ("claim", "lock_wait", "report", "gc")
+SPANS = ("claim", "lock_wait", "report", "gc", "dispatch", "block")
 
 _enabled = False
 _gc = {"collections": [0, 0, 0], "seconds": 0.0}
