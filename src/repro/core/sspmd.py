@@ -19,6 +19,10 @@ HLO + one collective per round).
 
 ``dca_round_assignments`` is the building block used by
 runtime/straggler.py (microbatch self-scheduling) and data/scheduler.py.
+
+Each round form opens its ``jax.named_scope`` (``tracing.SCOPES``), so its
+ops carry a stable name in their metadata for a device trace to attribute.
+A scope sets op metadata only; it adds no op.
 """
 
 from __future__ import annotations
@@ -57,24 +61,25 @@ def dca_round_assignments(round_state, tech_id, pv, axis_name: str):
     """
     i0, lp0 = round_state
     n_dev = jax.lax.axis_size(axis_name)
-    j = jax.lax.axis_index(axis_name)
+    with jax.named_scope("dca_round"):
+        j = jax.lax.axis_index(axis_name)
 
-    # Chunk calculation (distributed, the paper's Sec. 4): every device
-    # evaluates the closed form for all P steps of this round — O(P) flops,
-    # fully replicated, zero bytes on the wire.
-    steps = i0.astype(jnp.float32) + jnp.arange(n_dev, dtype=jnp.float32)
-    raw = jnp.maximum(jnp.round(sizes_for_steps(tech_id, steps, pv)), 1.0).astype(jnp.int32)
+        # Chunk calculation (distributed, the paper's Sec. 4): every device
+        # evaluates the closed form for all P steps of this round — O(P)
+        # flops, fully replicated, zero bytes on the wire.
+        steps = i0.astype(jnp.float32) + jnp.arange(n_dev, dtype=jnp.float32)
+        raw = jnp.maximum(jnp.round(sizes_for_steps(tech_id, steps, pv)), 1.0).astype(jnp.int32)
 
-    # Chunk assignment (the fetch-and-add): exclusive prefix sum over the
-    # round's sizes, clamped to the remaining iterations.
-    n_total = pv[0].astype(jnp.int32)
-    excl = jnp.cumsum(raw) - raw  # [P]
-    starts = lp0 + excl
-    sizes = jnp.clip(n_total - starts, 0, raw)
+        # Chunk assignment (the fetch-and-add): exclusive prefix sum over the
+        # round's sizes, clamped to the remaining iterations.
+        n_total = pv[0].astype(jnp.int32)
+        excl = jnp.cumsum(raw) - raw  # [P]
+        starts = lp0 + excl
+        sizes = jnp.clip(n_total - starts, 0, raw)
 
-    my_offset = starts[j]
-    my_size = sizes[j]
-    new_state = (i0 + n_dev, jnp.minimum(lp0 + jnp.sum(raw), n_total))
+        my_offset = starts[j]
+        my_size = sizes[j]
+        new_state = (i0 + n_dev, jnp.minimum(lp0 + jnp.sum(raw), n_total))
     return new_state, (my_offset, my_size)
 
 
@@ -98,13 +103,14 @@ def dca_round_assignments_stateless(round_idx, tech_id, pv, axis_name: str,
     must do the same.
     """
     n_dev = jax.lax.axis_size(axis_name)
-    j = jax.lax.axis_index(axis_name)
-    n_total = pv[0]
-    step = (jnp.asarray(round_idx, jnp.int32) * n_dev + j).astype(jnp.float32)
-    raw = jnp.clip(jnp.round(sizes_for_steps(tech_id, step, pv)), 1.0, n_total)
-    base = prefix_for_steps(tech_id, step, pv, head_cap=head_cap)
-    my_offset = jnp.clip(base, 0.0, n_total).astype(jnp.int32)
-    my_size = jnp.clip(n_total - base, 0.0, raw).astype(jnp.int32)
+    with jax.named_scope("dca_round"):
+        j = jax.lax.axis_index(axis_name)
+        n_total = pv[0]
+        step = (jnp.asarray(round_idx, jnp.int32) * n_dev + j).astype(jnp.float32)
+        raw = jnp.clip(jnp.round(sizes_for_steps(tech_id, step, pv)), 1.0, n_total)
+        base = prefix_for_steps(tech_id, step, pv, head_cap=head_cap)
+        my_offset = jnp.clip(base, 0.0, n_total).astype(jnp.int32)
+        my_size = jnp.clip(n_total - base, 0.0, raw).astype(jnp.int32)
     return my_offset, my_size
 
 
@@ -126,13 +132,14 @@ def dca_schedule_stateless(tech_name: str, params, axis_name: str,
     # size the prefix head to the largest step index actually evaluated —
     # steps stride by the mesh axis size, which may exceed params.P
     head_cap = default_head_cap(tech_name, params, max_rounds * n_dev + n_dev)
-    j = jax.lax.axis_index(axis_name)
-    n_total = pv[0]
-    steps = (jnp.arange(max_rounds, dtype=jnp.int32) * n_dev + j).astype(jnp.float32)
-    raw = jnp.clip(jnp.round(sizes_for_steps(tech_id, steps, pv)), 1.0, n_total)
-    base = prefix_for_steps(tech_id, steps, pv, head_cap=head_cap)
-    offs = jnp.clip(base, 0.0, n_total).astype(jnp.int32)
-    sizes = jnp.clip(n_total - base, 0.0, raw).astype(jnp.int32)
+    with jax.named_scope("dca_schedule"):
+        j = jax.lax.axis_index(axis_name)
+        n_total = pv[0]
+        steps = (jnp.arange(max_rounds, dtype=jnp.int32) * n_dev + j).astype(jnp.float32)
+        raw = jnp.clip(jnp.round(sizes_for_steps(tech_id, steps, pv)), 1.0, n_total)
+        base = prefix_for_steps(tech_id, steps, pv, head_cap=head_cap)
+        offs = jnp.clip(base, 0.0, n_total).astype(jnp.int32)
+        sizes = jnp.clip(n_total - base, 0.0, raw).astype(jnp.int32)
     return offs, sizes
 
 
@@ -169,7 +176,6 @@ def cca_round_assignments(round_state, tech_name: str, params, axis_name: str):
     """
     i0, lp0, prev, remaining = round_state
     n_dev = jax.lax.axis_size(axis_name)
-    j = jax.lax.axis_index(axis_name)
     p_f = jnp.float32(params.P)
 
     def step(carry, idx):
@@ -197,19 +203,22 @@ def cca_round_assignments(round_state, tech_name: str, params, axis_name: str):
 
     # Master-only compute: mask the scan's *result* by device id and broadcast
     # with a psum — workers idle while the master walks the chain.
-    (i_end, prev_end, rem_end), ks = jax.lax.scan(
-        step, (i0.astype(jnp.float32), prev, remaining), jnp.arange(n_dev)
-    )
-    is_master = (j == 0).astype(jnp.float32)
-    ks = jax.lax.psum(ks * is_master, axis_name)  # broadcast master's chunks
-    rem_end = jax.lax.psum(rem_end * is_master, axis_name)
-    prev_end = jax.lax.psum(prev_end * is_master, axis_name)
+    with jax.named_scope("cca_round"):
+        j = jax.lax.axis_index(axis_name)
+        (i_end, prev_end, rem_end), ks = jax.lax.scan(
+            step, (i0.astype(jnp.float32), prev, remaining), jnp.arange(n_dev)
+        )
+        is_master = (j == 0).astype(jnp.float32)
+        with jax.named_scope("cca_bcast"):
+            ks = jax.lax.psum(ks * is_master, axis_name)  # broadcast master's chunks
+            rem_end = jax.lax.psum(rem_end * is_master, axis_name)
+            prev_end = jax.lax.psum(prev_end * is_master, axis_name)
 
-    ks_i = ks.astype(jnp.int32)
-    excl = jnp.cumsum(ks_i) - ks_i
-    my_offset = lp0 + excl[j]
-    my_size = ks_i[j]
-    new_state = (i0 + n_dev, lp0 + jnp.sum(ks_i), prev_end, rem_end)
+        ks_i = ks.astype(jnp.int32)
+        excl = jnp.cumsum(ks_i) - ks_i
+        my_offset = lp0 + excl[j]
+        my_size = ks_i[j]
+        new_state = (i0 + n_dev, lp0 + jnp.sum(ks_i), prev_end, rem_end)
     return new_state, (my_offset, my_size)
 
 

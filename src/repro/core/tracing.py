@@ -20,6 +20,18 @@ Spans the program opens (``SPANS``):
 
 While tracing is on, ``gc_stats()`` also counts collections per generation
 and the seconds they took.
+
+Device scopes the program opens (``SCOPES``): ``jax.named_scope``s in the
+SPMD round forms (``core/sspmd.py``).  A scope only names the ops traced
+inside it, in their metadata (``op_name``), so it needs no switch and adds no
+op; a device trace finds it in each op's name path.
+
+* ``dca_schedule``: the closed-form chunk calculation of every round at once
+  (``dca_schedule_stateless``);
+* ``dca_round``: one DCA round (``dca_round_assignments`` and its stateless
+  form);
+* ``cca_round``: one CCA baseline round (``cca_round_assignments``);
+* ``cca_bcast``: inside it, the master's chunks broadcast by three ``psum``s.
 """
 
 from __future__ import annotations
@@ -28,9 +40,10 @@ import contextlib
 import gc
 import time
 
-__all__ = ["SPANS", "enabled", "on", "span", "gc_stats", "TimedLock"]
+__all__ = ["SPANS", "SCOPES", "enabled", "on", "span", "gc_stats", "TimedLock"]
 
 SPANS = ("claim", "lock_wait", "report", "gc", "dispatch", "block")
+SCOPES = ("dca_schedule", "dca_round", "cca_round", "cca_bcast")
 
 _enabled = False
 _gc = {"collections": [0, 0, 0], "seconds": 0.0}
