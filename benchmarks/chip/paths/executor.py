@@ -1,13 +1,23 @@
 """Host path: ``make_source(ScheduleSpec)`` feeding ``SelfSchedulingExecutor``.
 
 A frame builds a fresh source, and ``pes`` worker threads self-schedule its
-chunks.  A worker hands its chunk to the device-body adapter, which runs the
-device body on it one call per tile and waits for each result, as a PE of
-the paper finishes its chunk before it claims the next.  The frame ends when
-every chunk's result is ready.
+chunks.  A worker hands its chunk to the program's device-call layer,
+``repro.core.device_body.DeviceBody``, which runs the device body on it one
+call per tile.  The frame ends on this path's own wait, not the body's: once
+the executor returns, one batched ``jax.block_until_ready`` over every
+result, inside the timed frame, so a frame ends when the device has finished
+whether or not the body waits for each call.
+
+What the benchmark reads from the body:
+
+* ``results``: ``(lo, size, out)`` of every call, ``out`` of the tile's full
+  shape, lanes past ``size`` included (``Frame.tiles()``; ``check.py``
+  counts the masked lanes that do not read 0);
+* ``stamps``: one ``(dispatch_s, block_s)`` pair a call while traced
+  (``metrics/dispatch_us.py``, ``metrics/block_us.py``).
 
 A traced frame runs under the program's tracing switch: the executor, the
-sources and the adapter open their spans and stamp their records.
+sources and the body open their spans and stamp their records.
 """
 
 from __future__ import annotations
@@ -17,10 +27,10 @@ import time
 
 import numpy as np
 
-from device_body import DeviceBody
 from repro.core import tracing
+from repro.core.device_body import DeviceBody
 
-SPANS = tuple(dict.fromkeys(("make_source", "dispatch", "block") + tracing.SPANS))
+SPANS = ("make_source",) + tracing.SPANS
 
 
 class Frame:
@@ -73,4 +83,5 @@ class Runner:
         ex = SelfSchedulingExecutor(self.spec.technique, self.spec.to_params(), self.spec.mode,
                                     source=source, scenario=self.scenario)
         ex.run(body, n_workers=self.workers)
+        jax.block_until_ready([out for _, _, out in body.results])
         return Frame(view, ex.records, body, build_s, self.n)

@@ -1,12 +1,14 @@
-"""The device-body adapter on the CPU: one call per tile of a chunk, each
-waited for, results and stamps as its docstring says, under the executor as
-alone; and the host path taking this adapter and no other."""
+"""The host path's device-call layer on the CPU: the path takes the program's
+``DeviceBody`` and no copy of its own; a frame ends on the path's own
+batched wait over every result, also where the body waits for none; and the
+dispatch and block readers take one stamp a tile call from a traced frame.
+The body itself is tested in ``tests/test_device_body.py``."""
 
-import contextlib
 import importlib
+import importlib.util
 import sys
-import types
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -15,125 +17,109 @@ HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parents[1]
 sys.path[:0] = [str(HERE), str(ROOT / "src")]
 
-from device_body import DeviceBody  # noqa: E402
+import check  # noqa: E402
+import run  # noqa: E402
 from repro.core import tracing  # noqa: E402
+from repro.core.device_body import DeviceBody  # noqa: E402
 
-TILE = 1024
-
-
-@pytest.fixture(scope="module")
-def tile():
-    """Each iteration's index plus the offset argument; 0 past the tile's size."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def body(chunk, offset):
-        idx = chunk[0] + jnp.arange(TILE, dtype=jnp.int32)
-        return jnp.where(jnp.arange(TILE) < chunk[1], idx + offset, 0)
-    return body
+SEED = 2**31 + 12345
+HOST_CELLS = ["mandelbrot-t4.fac-dca", "mandelbrot-t4.fac-cca-slow100", "mandelbrot-t4.static-dca"]
 
 
 @pytest.fixture(scope="module")
-def offset():
+def built():
+    """``built(name, P)``: the app, reference schedule, runner and view of the
+    cell ``name`` at 64 x 64 pixels, threshold 16, ``P`` PEs; built once."""
     import jax
-    import jax.numpy as jnp
 
-    return jax.device_put(jnp.int32(7))
+    cache = {}
 
-
-@pytest.fixture
-def spans(monkeypatch):
-    """The names of the program spans opened, in order."""
-    opened, real = [], tracing.span
-
-    def span(name):
-        opened.append(name)
-        return real(name)
-    monkeypatch.setattr(tracing, "span", span)
-    return opened
-
-
-@pytest.mark.parametrize("lo,hi", [(0, 1024), (0, 3000), (2048, 2100), (5, 6), (100, 4196)],
-                         ids=["one_tile", "ragged_last", "shorter_than_a_tile", "one_iteration",
-                              "unaligned"])
-def test_results_equal_the_body_called_tile_by_tile(tile, offset, lo, hi):
-    body = DeviceBody(tile, TILE, offset)
-    body(lo, hi)
-    want = [(a, min(TILE, hi - a)) for a in range(lo, hi, TILE)]
-    assert [(a, size) for a, size, _ in body.results] == want
-    for (a, size, out), (b, bsize) in zip(body.results, want):
-        direct = tile(np.array([b, bsize], np.int32), offset)
-        assert np.array_equal(np.asarray(out), np.asarray(direct))
-        assert np.array_equal(np.asarray(out)[:size], np.arange(a, a + size) + 7)
-
-
-@pytest.mark.parametrize("technique,mode", [("fac", "dca"), ("fac", "cca"), ("static", "dca")])
-def test_executor_covers_every_iteration_once(tile, offset, technique, mode):
-    from repro.core.executor import SelfSchedulingExecutor
-    from repro.core.source import ScheduleSpec, make_source
-
-    n = 4096
-    spec = ScheduleSpec(technique, N=n, P=16, mode=mode)
-    body = DeviceBody(tile, TILE, offset)
-    ex = SelfSchedulingExecutor(spec.technique, spec.to_params(), spec.mode,
-                                source=make_source(spec))
-    ex.run(body, n_workers=4)
-    got = np.zeros(n, np.int64)
-    for a, size, out in body.results:
-        got[a:a + size] += 1
-        assert np.array_equal(np.asarray(out)[:size], np.arange(a, a + size) + 7)
-    assert np.all(got == 1)
-    assert len(body.results) == len(ex.records)  # no chunk exceeds a tile here
-
-
-def test_untraced_body_opens_no_span_and_stamps_nothing(tile, offset, spans):
-    body = DeviceBody(tile, TILE, offset)
-    with tracing.on():  # switched on after the body was built: it stays untraced
-        body(0, 3000)
-    assert spans == [] and body.stamps == [] and len(body.results) == 3
-
-
-def test_traced_body_opens_both_spans_and_stamps_each_call(tile, offset, spans):
-    with tracing.on():
-        body = DeviceBody(tile, TILE, offset)
-        body(0, 3000)
-        body(4000, 4096)
-    assert spans == ["dispatch", "block"] * 4
-    assert len(body.stamps) == len(body.results) == 4
-    assert all(d > 0 and b >= 0 for d, b in body.stamps)
-
-
-@pytest.mark.parametrize("traced", [False, True])
-def test_an_error_in_the_body_reaches_the_caller(traced):
-    def tile(chunk, offset):
-        raise ValueError(f"no tile at {int(chunk[0])}")
-
-    with tracing.on() if traced else contextlib.nullcontext():
-        body = DeviceBody(tile, TILE, 0)
-    with pytest.raises(ValueError, match="no tile at 0"):
-        body(0, 10)
-    assert body.results == [] and body.stamps == []
+    def get(name, p=16):
+        if (name, p) not in cache:
+            cell = run.load_cell(name)
+            cell.config.update(N=4096, width=64, threshold=16, P=p)
+            app, schedule, runner = run.build(cell, jax.devices()[:1])
+            view = next(run.frames_of(app, cell.config, SEED))
+            cache[name, p] = SimpleNamespace(app=app, cfg=cell.config, schedule=schedule,
+                                             runner=runner, view=view)
+        return cache[name, p]
+    return get
 
 
 @pytest.fixture
-def fresh_path(monkeypatch):
-    """``paths.executor`` imported anew, after the test has set up its modules."""
-    monkeypatch.delitem(sys.modules, "paths.executor", raising=False)
-    yield lambda: importlib.import_module("paths.executor")
-    sys.modules.pop("paths.executor", None)
+def waits(monkeypatch):
+    """The leaves handed to each ``jax.block_until_ready`` call, in order."""
+    import jax
+
+    calls, real = [], jax.block_until_ready
+
+    def wait(x):
+        calls.append(jax.tree_util.tree_leaves(x))
+        return real(x)
+    monkeypatch.setattr(jax, "block_until_ready", wait)
+    return calls
 
 
+def outputs(frame):
+    return [out for _, _, out in frame._results]
 
-@pytest.mark.parametrize("program_module", [False, True])
-def test_host_path_takes_the_benchmarks_adapter(fresh_path, monkeypatch, program_module):
-    """The path's adapter is this benchmark's, also where the program has a
-    module of that name: no file outside the benchmark can swap it."""
-    program = None
-    if program_module:
-        program = types.ModuleType("repro.core.device_body")
-        program.DeviceBody = type("DeviceBody", (DeviceBody,), {})
-    monkeypatch.setitem(sys.modules, "repro.core.device_body", program)
-    path = fresh_path()
+
+def test_host_path_takes_the_programs_device_body():
+    path = importlib.import_module("paths.executor")
     assert path.DeviceBody is DeviceBody
-    assert {"make_source", "dispatch", "block", *tracing.SPANS} == set(path.SPANS)
+    assert importlib.util.find_spec("device_body") is None
+    assert {"make_source", *tracing.SPANS} == set(path.SPANS)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+@pytest.mark.parametrize("name", HOST_CELLS)
+def test_frame_waits_on_every_result_before_it_returns(built, waits, name, traced):
+    b = built(name)
+    frame = b.runner.frame(b.view, traced=traced)
+    outs = outputs(frame)
+    assert len(outs) == len(frame.chunks()) >= 1
+    # one batched wait, handed every output once the executor had returned
+    assert [[id(a) for a in leaves] for leaves in waits] == [[id(a) for a in outs]]
+    assert all(out.is_ready() for out in outs)
+
+
+class NoWait(DeviceBody):
+    """Dispatches every tile of a chunk and waits for none."""
+
+    def __call__(self, lo, hi):
+        for a in range(lo, hi, self.tile_size):
+            size = min(self.tile_size, hi - a)
+            self.results.append((a, size, self.tile(np.array([a, size], np.int32), *self.args)))
+
+
+@pytest.mark.parametrize("name", HOST_CELLS)
+def test_frame_of_a_body_that_does_not_wait_ends_ready_and_correct(built, waits, monkeypatch,
+                                                                     name):
+    b = built(name)
+    monkeypatch.setattr(importlib.import_module("paths.executor"), "DeviceBody", NoWait)
+    frame = b.runner.frame(b.view)
+    outs = outputs(frame)
+    assert [[id(a) for a in leaves] for leaves in waits] == [[id(a) for a in outs]]
+    assert all(out.is_ready() for out in outs)
+
+    def reference(view):
+        return b.app.reference(view, b.cfg["N"], b.cfg["width"], b.cfg["threshold"])
+
+    checks = check.compare([frame], reference, b.schedule, b.cfg["N"])
+    assert check.passed(checks), checks
+
+
+@pytest.mark.parametrize("name", HOST_CELLS)
+@pytest.mark.parametrize("reader,part", [("dispatch_us", 0), ("block_us", 1)])
+def test_reader_takes_one_stamp_per_tile_call(built, reader, part, name):
+    b = built(name, p=2)  # static's chunks span two tiles at P = 2
+    read = importlib.import_module(f"metrics.{reader}").read
+    frames = [b.runner.frame(b.view, traced=True) for _ in range(2)]
+    for frame in frames:
+        calls = sum(-(-size // b.app.TILE) for _, _, size in frame.chunks())
+        assert len(frame.stamps) == len(frame.tiles()) == calls
+    stamps = [s[part] for frame in frames for s in frame.stamps]
+    assert read(SimpleNamespace(frames=frames)) == pytest.approx(1e6 * np.mean(stamps))
+    assert read(SimpleNamespace(frames=frames)) > 0
+    untraced = b.runner.frame(b.view)
+    assert untraced.stamps == [] and read(SimpleNamespace(frames=[untraced])) is None
